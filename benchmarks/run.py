@@ -62,6 +62,8 @@ def main(argv=None) -> None:
     ap.add_argument("--steps", type=int, default=None,
                     help="train steps for the substrate model")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     t0 = time.time()
     print("name,us_per_call,derived")

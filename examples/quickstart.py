@@ -23,6 +23,7 @@ from repro.configs import ALL_ARCHS, get_smoke_config
 from repro.core import CompressConfig, compress_model
 from repro.core.pipeline import compress_ratio_report
 from repro.data import calibration_set, synthetic_tokens
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.serve import Server
 from repro.models import model as M
 
@@ -57,6 +58,7 @@ def main():
                     help="skip stage-2 block refinement (closed-form solve "
                          "only)")
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = get_smoke_config(args.arch).replace(dtype="float32")
     params = M.init_params(cfg, jax.random.PRNGKey(0))

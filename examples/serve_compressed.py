@@ -20,6 +20,7 @@ import jax
 from repro.configs import ALL_ARCHS, get_smoke_config
 from repro.core import CompressConfig, compress_model
 from repro.data import calibration_set, synthetic_tokens
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.serve import Server
 from repro.models import model as M
 
@@ -38,6 +39,7 @@ def main():
     ap.add_argument("--ratio", type=float, default=0.6)
     ap.add_argument("--batch", type=int, default=4)
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = get_smoke_config(args.arch).replace(dtype="float32")
     params = M.init_params(cfg, jax.random.PRNGKey(0))

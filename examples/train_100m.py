@@ -15,6 +15,7 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs import get_config
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.train import train
 
 
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--d-model", type=int, default=768)
     ap.add_argument("--layers", type=int, default=12)
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = get_config("llama-7b").replace(
         name="llama-100m",

@@ -1,7 +1,7 @@
 """qwen3-0.6b [dense] — qk_norm + GQA.
 
 28L d_model=1024 16H (GQA kv=8) d_ff=3072 vocab=151936.  head_dim=128
-(explicit, as in the Qwen3 family).  [hf:Qwen/Qwen3-8B; hf]
+(explicit, as in the Qwen3 family).  [hf:Qwen/Qwen3-0.6B; hf]
 """
 
 from repro.configs.base import ModelConfig

@@ -17,6 +17,16 @@ for parity with SVD-LLM.  Both are covered by the same theorem (App. A).
 
 Everything here operates on n×n covariances, never raw activations, so cost
 is independent of the calibration token count (App. B.1).
+
+Decompositions on TPU.  The TPU's default ``eigh`` and ``svd`` (QDWH
+divide-and-conquer) take minutes to compile at these widths — 82 s for one
+1024×1024 ``eigh`` on a v5e, and a qwen3-0.6b compression spent over 15
+minutes compiling its solves.  On TPU, ``_eigh`` therefore uses the native
+Jacobi eigensolver, which compiles in seconds, and ``_svd`` is taken from
+the Jacobi eigendecomposition of the smaller Gram matrix.  Every matmul
+of the solve runs at HIGHEST precision: the TPU's default takes an fp32
+matmul in one bf16 pass, which the Gram matrix's squared spectrum cannot
+afford.  Other platforms keep their LAPACK-backed ``eigh``/``svd``.
 """
 
 from __future__ import annotations
@@ -26,13 +36,52 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.lax.linalg import EighImplementation
+
+_mm = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+
+
+def _eigh_jacobi(a):
+    v, w = jax.lax.linalg.eigh(a, symmetrize_input=False,
+                               implementation=EighImplementation.JACOBI)
+    return w, v
+
+
+def _eigh(a):
+    """(ascending eigenvalues, eigenvectors) of a symmetric fp32 matrix."""
+    return jax.lax.platform_dependent(
+        a, tpu=_eigh_jacobi, default=lambda x: tuple(jnp.linalg.eigh(x)))
+
+
+def _svd_via_gram(mat, eigh=_eigh_jacobi):
+    """Thin SVD (u, s, vt) of ``mat`` (m, n) from the eigendecomposition of
+    its smaller Gram matrix, singular values descending.  Directions whose
+    singular value is zero get zero vectors on the other side (they carry
+    no energy, so every rank-k product is unchanged)."""
+    m, n = mat.shape
+    tall = m >= n
+    gram = _mm(mat.T, mat) if tall else _mm(mat, mat.T)
+    w, q = eigh(gram)
+    w, q = w[::-1], q[:, ::-1]
+    s = jnp.sqrt(jnp.maximum(w, 0.0))
+    inv = jnp.where(s > 0, 1.0 / jnp.where(s > 0, s, 1.0), 0.0)
+    if tall:
+        return _mm(mat, q) * inv[None, :], s, q.T
+    return q, s, _mm(q.T, mat) * inv[:, None]
+
+
+def _svd(mat):
+    """Thin SVD (u, s, vt) of a fp32 matrix; see the module docstring."""
+    return jax.lax.platform_dependent(
+        mat, tpu=_svd_via_gram,
+        default=lambda x: tuple(jnp.linalg.svd(x, full_matrices=False)))
 
 
 def _svd_truncate(mat: jnp.ndarray, k: int):
     """Rank-k SVD factors of ``mat`` (m, n) plus the FULL spectrum (the
     same decomposition serves the solve and the adaptive loss estimate):
     returns (A (m,k), B (n,k), σ) with mat ≈ A @ B.T."""
-    u, s, vt = jnp.linalg.svd(mat.astype(jnp.float32), full_matrices=False)
+    u, s, vt = _svd(mat.astype(jnp.float32))
     return u[:, :k] * s[:k][None, :], vt[:k].T, s
 
 
@@ -57,7 +106,7 @@ def _whitening_factors(s_cov: jnp.ndarray, *, eps: float, method: str):
         l_inv_t = jax.scipy.linalg.solve_triangular(
             l_fac, jnp.eye(n, dtype=s_cov.dtype), lower=True).T
         return l_fac, l_inv_t
-    lam, q = jnp.linalg.eigh(s_cov)
+    lam, q = _eigh(s_cov)
     floor = eps * jnp.maximum(jnp.max(lam), 1e-12)
     lam = jnp.maximum(lam, floor)                     # Tikhonov clamp
     sqrt_lam = jnp.sqrt(lam)
@@ -77,9 +126,9 @@ def _anchored_core(w, cov_ab, cov_bb, k: int, eps: float, method: str):
     l_fac, l_inv_t = _whitening_factors(cov_bb.astype(jnp.float32),
                                         eps=eps, method=method)
     # M = W C S^{-1} L = W C L^{-T}   (since S^{-1} L = L^{-T})
-    mat = wf.T @ (cov_ab.astype(jnp.float32) @ l_inv_t)        # (m, n)
+    mat = _mm(wf.T, _mm(cov_ab.astype(jnp.float32), l_inv_t))  # (m, n)
     a_fac, b_fac, s = _svd_truncate(mat, k)                    # M ≈ A Bᵀ
-    v = l_inv_t @ b_fac                                        # (n, k)
+    v = _mm(l_inv_t, b_fac)                                    # (n, k)
     u = a_fac.T                                                # (k, m)
     return {"v": v, "u": u}, s
 
@@ -137,15 +186,15 @@ def whitened_spectrum(w: jnp.ndarray, cov_ab: jnp.ndarray,
     wf = w.astype(jnp.float32)
     _, l_inv_t = _whitening_factors(cov_bb.astype(jnp.float32),
                                     eps=eps, method=method)
-    mat = wf.T @ (cov_ab.astype(jnp.float32) @ l_inv_t)
-    return jnp.linalg.svd(mat, compute_uv=False)
+    mat = _mm(wf.T, _mm(cov_ab.astype(jnp.float32), l_inv_t))
+    return _svd(mat)[1]
 
 
 @jax.jit
 def weight_spectrum(w: jnp.ndarray) -> jnp.ndarray:
     """Plain singular values of W — the agnostic-objective analogue of
     ``whitened_spectrum`` (Eckart–Young tail energy)."""
-    return jnp.linalg.svd(w.astype(jnp.float32), compute_uv=False)
+    return _svd(w.astype(jnp.float32))[1]
 
 
 def spectrum_tail_energy(spectrum, k: int) -> float:

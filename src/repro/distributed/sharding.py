@@ -25,14 +25,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 PyTree = Any
 
-# jax.shard_map graduated from jax.experimental in newer releases; resolve
-# one alias here so model code runs on both.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-
 def fsdp_axes(mesh: Mesh):
     return ("pod", "data") if "pod" in mesh.axis_names else ("data",)
 
@@ -211,10 +203,37 @@ def tree_shardings(tree: PyTree, mesh: Mesh, spec_fn) -> PyTree:
     return jax.tree_util.tree_map_with_path(one, tree)
 
 
+_STAGE_RE = re.compile(r"^(encoder/)?stages/(\d+)/")
+
+
+def _stacked_stages(cfg):
+    """(encoder?, stage index) of every stage whose params carry a leading
+    layer-stack dim (scanned stages of more than one iteration)."""
+    from repro.models import blocks as B
+
+    out = set()
+    for enc, stages in ((False, B.stage_program(cfg)),
+                        (True, B.encoder_stages(cfg) or [])):
+        out |= {(enc, i) for i, st in enumerate(stages)
+                if st.scan and st.n > 1}
+    return out
+
+
 def param_shardings(params: PyTree, mesh: Mesh, mode: str = "store",
                     cfg=None) -> PyTree:
-    return tree_shardings(params, mesh,
-                          lambda p, s: param_spec(p, s, mesh, mode, cfg))
+    """Per-leaf shardings of a params-shaped tree.  With ``cfg``, leaves of
+    scanned stages keep their leading layer-stack dim unsharded and the
+    rules apply to the per-layer shape — so expert banks shard over their
+    expert axis, not over the layer axis."""
+    stacked = _stacked_stages(cfg) if cfg is not None else set()
+
+    def spec(path, shape):
+        m = _STAGE_RE.match(path)
+        if m and (bool(m.group(1)), int(m.group(2))) in stacked:
+            return P(None, *param_spec(path, shape[1:], mesh, mode, cfg))
+        return param_spec(path, shape, mesh, mode, cfg)
+
+    return tree_shardings(params, mesh, spec)
 
 
 def param_use_hints(p: PyTree) -> PyTree:
@@ -297,19 +316,13 @@ def cov_spec(mesh: Mesh) -> P:
 
 
 def data_shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across JAX versions.
+    """``shard_map`` with varying-axes checking off.
 
     The SPMD cov path in ``kernels.ops`` maps a Pallas call over the data
-    axes; ``pallas_call`` carries no replication rule, so the rep checker
-    must be disabled.  The kwarg was renamed ``check_rep`` -> ``check_vma``
-    when shard_map graduated from jax.experimental — try both."""
-    try:
-        # repro-check: allow[raw-unreplicated-shardmap] — this IS the blessed wrapper the rule routes callers to
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:
-        # repro-check: allow[raw-unreplicated-shardmap] — check_vma spelling of the same blessed wrapper
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
+    axes; ``pallas_call`` carries no replication rule, so the check must be
+    disabled."""
+    # repro-check: allow[raw-unreplicated-shardmap] — this IS the blessed wrapper the rule routes callers to
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
 
 

@@ -41,6 +41,7 @@ of the lattice and are not persisted.  See ``kernels/README.md``.
 from __future__ import annotations
 
 import json
+import logging
 import math
 import os
 import tempfile
@@ -52,6 +53,8 @@ import jax.numpy as jnp
 import numpy as np
 
 CACHE_VERSION = 1
+
+log = logging.getLogger(__name__)
 
 # hand-tuned anchors: the block shapes ops.py shipped with before the
 # autotuner existed — the heuristic's preferred point on each lattice
@@ -359,12 +362,17 @@ def flash_decode_candidates(l: int, d: int, rk: int, rv: int, kv: int,
 
 def _time_call(fn: Callable, args: tuple, warmup: int = 1,
                iters: int = 3) -> float:
+    """Median µs per call of ``fn`` compiled ahead of time for ``args``.
+
+    The explicit lower/compile keeps the timed call off any trace that is
+    active around the tuner, so the time is the compiled kernel's."""
+    compiled = jax.jit(fn).lower(*args).compile()
     for _ in range(warmup):
-        jax.block_until_ready(fn(*args))
+        jax.block_until_ready(compiled(*args))
     ts = []
     for _ in range(iters):
         t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
+        jax.block_until_ready(compiled(*args))
         ts.append((time.perf_counter() - t0) * 1e6)
     return float(np.median(ts))
 
@@ -374,19 +382,35 @@ def _measure_best(cands: Sequence[Candidate],
                   ) -> Tuple[Candidate, float]:
     """Time the top preference-ranked candidates (compiled-call medians)
     and return the fastest.  ``thunk(cand) -> (fn, args)`` builds the
-    kernel call for one candidate; a candidate whose compile or run fails
-    (e.g. an interpret-mode limitation) is skipped."""
+    kernel call for one candidate.
+
+    The tuner is consulted while an outer function is being traced, where
+    every array op would be staged into that trace: the probe arrays are
+    built concretely (``ensure_compile_time_eval``) and ``_time_call``
+    runs a separately compiled kernel, so the timing measures the kernel
+    and not tracing.  A candidate whose compile or run fails is logged
+    with its blocks and skipped; if every candidate fails, the error is
+    raised — a kernel the compiler rejects never hides behind a default
+    block choice."""
     best: Optional[Tuple[Candidate, float]] = None
+    failures = []
     for cand in list(cands)[:_max_measured()]:
-        fn, args = thunk(cand)
         try:
+            with jax.ensure_compile_time_eval():
+                fn, args = thunk(cand)
             us = _time_call(fn, args)
-        except Exception:  # noqa: BLE001; repro-check: allow[bare-except] — a failing candidate (compile/run error) is just skipped
+        except Exception as e:  # noqa: BLE001; repro-check: allow[bare-except] — logged with its blocks; raised below when no candidate survives
+            log.warning("autotune: candidate %s failed: %s: %s",
+                        cand.blocks, type(e).__name__, e)
+            failures.append((cand.blocks, e))
             continue
         if best is None or us < best[1]:
             best = (cand, us)
-    if best is None:  # every candidate failed: fall back to the heuristic
-        return cands[0], float("nan")
+    if best is None:
+        blocks = [b for b, _ in failures]
+        raise RuntimeError(
+            f"autotune: every measured candidate failed {blocks}; "
+            f"last error: {failures[-1][1]}") from failures[-1][1]
     return best
 
 
@@ -404,10 +428,8 @@ def _tune(kernel: str, sig: str, cands: Sequence[Candidate],
                              entry.get("us"))
         else:
             cand, us = _measure_best(cands, thunk)
-            res = TuneResult(dict(cand.blocks), "measured",
-                             None if math.isnan(us) else us)
-            if res.us is not None:
-                _disk_put(key, {"blocks": res.blocks, "us": res.us})
+            res = TuneResult(dict(cand.blocks), "measured", us)
+            _disk_put(key, {"blocks": res.blocks, "us": res.us})
     else:  # heuristic (and "off", which is the anchor-flavoured heuristic)
         res = TuneResult(dict(cands[0].blocks), "heuristic", None)
     _MEM[key + f"|{resolved}"] = res

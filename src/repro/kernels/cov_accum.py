@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _kernel(x_i, x_j, xp_i, xp_j, xx, xxp, xpxp):
     t = pl.program_id(2)
@@ -86,6 +84,6 @@ def cov_accum(x, xp, *, bi: int = 256, bt: int = 512,
         ],
         out_shape=[out, out, out],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, x, xp, xp)

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -48,7 +47,7 @@ def _kernel(scale: float, use_rope: bool, kv: int, g: int, d: int, bk: int,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0)]
     lkb = lk_ref[0].astype(jnp.float32)                       # (bk, r_k)
     half = d // 2
     rows = []
@@ -121,8 +120,9 @@ def flash_decode(q, lk, lv, uk, uv, lengths, cos, sin, *,
         kernel,
         grid=(b, l // bk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0),
-                         memory_space=pltpu.SMEM),
+            # whole (B,) lengths vector in SMEM: a per-slot (1,) block
+            # would break the TPU's (8, 128) block-tiling rule
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, h, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, bk, rk), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, bk, rv), lambda i, j: (i, j, 0)),
@@ -139,6 +139,6 @@ def flash_decode(q, lk, lv, uk, uv, lengths, cos, sin, *,
             pltpu.VMEM((h, rv), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-    )(lengths.reshape(b, 1).astype(jnp.int32), q, lk, lv, uk, uv, cos, sin)
+    )(lengths.astype(jnp.int32), q, lk, lv, uk, uv, cos, sin)

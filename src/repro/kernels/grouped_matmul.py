@@ -41,8 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _metadata(group_sizes, m_pad: int, bm: int, num_tiles: int):
     """Per-tile scalars from the traced group sizes.
@@ -137,6 +135,6 @@ def grouped_matmul(x, w, group_sizes, *, bm: int = 128, bf: int = 256,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, f), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(*meta, x, w)

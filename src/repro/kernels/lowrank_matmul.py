@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _kernel(n_steps: int, has_bias: bool, has_res: bool, *refs):
     it = iter(refs)
@@ -109,6 +107,6 @@ def lowrank_matmul(x, v, u, bias=None, residual=None, *, bt: int = 256,
         out_shape=jax.ShapeDtypeStruct((t_dim, m), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, k), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(*inputs)

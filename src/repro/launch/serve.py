@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import time
 from typing import Any, Dict, List, Optional
 
@@ -55,8 +56,10 @@ import numpy as np
 from repro.configs import ALL_ARCHS, get_config, get_smoke_config
 from repro.core import CompressConfig, compress_model
 from repro.data import calibration_set, synthetic_tokens
+from repro.distributed import sharding as SH
 from repro.launch import steps as S
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import setup_compile_cache
+from repro.launch.mesh import serving_mesh
 from repro.models import model as M
 
 
@@ -80,17 +83,41 @@ def _prefill_extra_len(cfg) -> int:
 
 
 class Server:
-    """Fixed-batch serving frontend (one prefill + lock-step decode)."""
+    """Fixed-batch serving frontend (one prefill + lock-step decode).
+
+    Under a mesh, params are laid out by ``distributed.sharding`` (expert
+    banks split over ``model``, TP elsewhere) and the cache by its
+    per-leaf rules; every step is jitted with those shardings, so no
+    device holds more than its share.  Params that already carry that
+    layout (e.g. initialized through ``jit`` ``out_shardings``) stay
+    where they are."""
 
     def __init__(self, cfg, params, *, max_len: int = 256, batch: int = 4,
                  mesh=None):
         self.cfg = cfg
-        self.params = params
         self.max_len = max_len
         self.batch = batch
-        mesh = mesh or make_host_mesh()
-        self._serve = jax.jit(S.make_serve_step(cfg, mesh))
-        self._prefill = jax.jit(S.make_prefill_step(cfg, mesh))
+        mesh = serving_mesh(mesh)
+        init_cache = functools.partial(M.init_cache, cfg, batch, max_len)
+        serve = S.make_serve_step(cfg, mesh)
+        prefill = S.make_prefill_step(cfg, mesh)
+        if mesh is None:
+            # host arrays (a checkpoint restore) go to the device once, not
+            # on every step
+            self.params = jax.device_put(params)
+            self._init_cache = init_cache
+            self._serve = jax.jit(serve)
+            self._prefill = jax.jit(prefill)
+            return
+        psh, csh = S.decode_shardings(cfg, mesh, params,
+                                      jax.eval_shape(init_cache))
+        rep = SH.replicated(mesh)
+        self.params = jax.device_put(params, psh)
+        self._init_cache = jax.jit(init_cache, out_shardings=csh)
+        self._serve = jax.jit(serve, in_shardings=(psh, csh, rep, None),
+                              out_shardings=(rep, csh))
+        self._prefill = jax.jit(prefill, in_shardings=(psh, rep, csh),
+                                out_shardings=(rep, csh))
 
     @classmethod
     def from_checkpoint(cls, cfg, directory: str, *, step: int = None,
@@ -134,7 +161,7 @@ class Server:
         prompts = _pad_batch(prompts, self.batch)
         extras = {k: _pad_batch(jnp.asarray(v), self.batch)
                   for k, v in (extras or {}).items()}
-        cache = M.init_cache(self.cfg, self.batch, self.max_len)
+        cache = self._init_cache()
         batch = {"tokens": prompts, **extras}
         next_tok, cache = self._prefill(self.params, batch, cache)
         out = [next_tok[:, None]]
@@ -177,7 +204,7 @@ class ContinuousBatchingServer:
                  prefill_chunk: int = 0, mesh=None,
                  cache_layout: str = "auto"):
         self.cfg = cfg
-        self.params = params
+        self.params = jax.device_put(params)
         self.max_len = max_len
         self.slots = slots
         self.prefill_chunk = prefill_chunk
@@ -185,7 +212,7 @@ class ContinuousBatchingServer:
         # tolerate right-padded prompts -> exact-length whole prefill.
         self._exact = (cfg.family in ("ssm", "hybrid")
                        or cfg.attention == "sliding_mix")
-        mesh = mesh or make_host_mesh()
+        mesh = serving_mesh(mesh)
         self._decode = jax.jit(S.make_serve_step(cfg, mesh),
                                donate_argnums=(1,))
         self._pre_whole = jax.jit(S.make_slot_prefill_step(cfg, mesh,
@@ -345,6 +372,7 @@ def main():
     ap.add_argument("--engine", action="store_true",
                     help="route through the continuous-batching engine")
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.smoke:

@@ -35,6 +35,7 @@ from repro.configs import ALL_ARCHS, get_config, get_smoke_config
 from repro.data import make_batch_iterator
 from repro.distributed import sharding as SH
 from repro.launch import steps as S
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.optim import AdamWConfig, adamw, compression
 
@@ -131,6 +132,7 @@ def main():
     ap.add_argument("--grad-compression", action="store_true")
     ap.add_argument("--step-deadline-s", type=float, default=0.0)
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.smoke:

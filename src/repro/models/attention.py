@@ -263,10 +263,10 @@ def _decode_stats(q, k, v, key_offset, pos, chunk: int, vary_axes=()):
     m0 = jnp.full((b, h, lq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, lq), jnp.float32)
     a0 = jnp.zeros((b, h, lq, d), jnp.float32)
-    if vary_axes and hasattr(jax.lax, "pvary"):
-        # newer jax tracks varying axes explicitly; older releases have no
-        # pvary and treat shard_map carries as varying already
-        m0, l0, a0 = (jax.lax.pvary(t, tuple(vary_axes))
+    if vary_axes:
+        # shard_map tracks varying axes: the scan carry must vary like the
+        # per-shard inputs it is combined with
+        m0, l0, a0 = (jax.lax.pcast(t, tuple(vary_axes), to="varying")
                       for t in (m0, l0, a0))
     (m, l_sum, acc), _ = jax.lax.scan(body, (m0, l0, a0),
                                       jnp.arange(n_chunks))
@@ -300,7 +300,7 @@ def _seqpar_flash_decode(q, cache_k, cache_v, pos, mesh, *, chunk: int):
         out = acc_g / jnp.maximum(l_g, 1e-20)[..., None]   # (B, H, 1, D)
         return out.transpose(0, 2, 1, 3).astype(q_blk.dtype)
 
-    return SH.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None, None), P(dp, "model", None, None),
                   P(dp, "model", None, None)),

@@ -410,7 +410,7 @@ def _moe_apply_ep(p, x, cfg, mesh, capacity_factor: float, dispatch: str):
         y = jax.lax.psum(y.astype(x_blk.dtype), "model")
         return y.reshape(bl, l, d), aux
 
-    y, aux = SH.shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None),
                   _bank_spec(p["experts"], mesh)),
@@ -551,7 +551,7 @@ def _moe_apply_ep_decode(p, x, cfg, mesh, capacity_factor: float,
         y = jax.lax.dynamic_slice_in_dim(y, dp_index() * bl * l, bl * l, 0)
         return y.reshape(bl, l, d), aux
 
-    y, aux = SH.shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None),
                   jax.tree.map(lambda a: P("model", dp, None), p["experts"])),

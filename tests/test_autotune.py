@@ -145,3 +145,60 @@ def test_tuned_blocks_match_default_on_unaligned_shapes(monkeypatch):
             np.testing.assert_allclose(np.asarray(o), np.asarray(w),
                                        rtol=1e-4, atol=1e-4,
                                        err_msg=mode)
+
+
+def test_measure_raises_when_every_candidate_fails(caplog):
+    """A kernel the compiler rejects in every block shape surfaces as an
+    error naming the blocks tried — never as a silent default pick."""
+    cands = autotune.cov_candidates(512, 512)[:2]
+
+    def thunk(c):
+        def broken(x):
+            raise ValueError(f"rejected {c.blocks}")
+        return broken, (jnp.ones((8, 128)),)
+
+    with caplog.at_level("WARNING", logger="repro.kernels.autotune"):
+        with pytest.raises(RuntimeError, match="every measured candidate"):
+            autotune._measure_best(cands, thunk)
+    logged = " ".join(r.getMessage() for r in caplog.records)
+    for c in cands:
+        assert str(c.blocks) in logged
+
+
+def test_measure_skips_a_failing_candidate(caplog):
+    """One failing candidate is logged and skipped; the survivor wins."""
+    cands = autotune.cov_candidates(512, 512)[:2]
+
+    def thunk(c):
+        if c is cands[0]:
+            def broken(x):
+                raise ValueError("rejected")
+            return broken, (jnp.ones((8, 128)),)
+        return (lambda x: x * 2), (jnp.ones((8, 128)),)
+
+    with caplog.at_level("WARNING", logger="repro.kernels.autotune"):
+        best, us = autotune._measure_best(cands, thunk)
+    assert best is cands[1] and us > 0
+    assert str(cands[0].blocks) in caplog.text
+
+
+def test_measure_inside_a_trace_times_concrete_arrays(monkeypatch):
+    """The tuner is consulted while the calling step is being traced; the
+    timed call must still get concrete arrays (a staged call would time
+    tracing, and a compile error would only show at the outer compile)."""
+    seen = []
+    real = autotune._time_call
+
+    def spy(fn, args, **kw):
+        seen.extend(type(a) for a in args if a is not None)
+        return real(fn, args, **kw)
+
+    monkeypatch.setattr(autotune, "_time_call", spy)
+    from repro.kernels import ops
+
+    f = jax.jit(lambda x: ops.cov_accum(x, x, force_pallas=True,
+                                        interpret=True))
+    monkeypatch.setenv("REPRO_AUTOTUNE", "measure")
+    f(jnp.ones((256, 256)))
+    assert seen and all(issubclass(t, jax.Array) for t in seen)
+    assert not any(issubclass(t, jax.core.Tracer) for t in seen)

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import CheckpointManager
+from repro.launch.mesh import make_mesh
 
 
 def state_like(seed=0):
@@ -61,7 +62,7 @@ class TestCheckpoint:
         mgr = CheckpointManager(str(tmp_path), async_save=False)
         st = state_like(7)
         mgr.save(7, st, blocking=True)
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         from jax.sharding import NamedSharding, PartitionSpec as P
         sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), st)
         step, got = mgr.restore(None, jax.eval_shape(lambda: st), sh)

@@ -67,6 +67,7 @@ COMMON = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config
 from repro.distributed import sharding as SH
+from repro.launch.mesh import make_mesh
 """
 
 
@@ -77,7 +78,7 @@ cfg = get_smoke_config("deepseek-v2-lite-16b").replace(dtype="float32")
 p = mlp.moe_init(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 128, cfg.d_model)) * 0.5
 y_ref, aux_ref = mlp.moe_apply(p, x, cfg, capacity_factor=64.0)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 def f(p, x):
     with SH.use_mesh(mesh, cfg=cfg):
         return mlp.moe_apply(p, x, cfg, capacity_factor=64.0)
@@ -95,7 +96,7 @@ cfg = get_smoke_config("deepseek-v2-lite-16b").replace(dtype="float32")
 p = mlp.moe_init(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 1, cfg.d_model)) * 0.5
 y_ref, aux_ref = mlp.moe_apply(p, x, cfg, capacity_factor=64.0)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 def f(p, x):
     with SH.use_mesh(mesh, cfg=cfg):
         return mlp.moe_apply(p, x, cfg, capacity_factor=64.0)
@@ -117,7 +118,7 @@ cfg = get_smoke_config("deepseek-v2-lite-16b").replace(dtype="float32")
 p = mlp.moe_init(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 128, cfg.d_model)) * 0.5
 y_ref, aux_ref = mlp.moe_apply(p, x, cfg, dispatch="dropfree")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 def f(p, x):
     with SH.use_mesh(mesh, cfg=cfg):
         return mlp.moe_apply(p, x, cfg, dispatch="dropfree")
@@ -135,7 +136,7 @@ cfg = get_smoke_config("deepseek-v2-lite-16b").replace(dtype="float32")
 p = mlp.moe_init(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 1, cfg.d_model)) * 0.5
 y_ref, aux_ref = mlp.moe_apply(p, x, cfg, dispatch="dropfree")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 def f(p, x):
     with SH.use_mesh(mesh, cfg=cfg):
         return mlp.moe_apply(p, x, cfg, dispatch="dropfree")
@@ -159,7 +160,7 @@ k = jax.random.normal(jax.random.PRNGKey(1), (B, L, KV, D), jnp.float32)
 v = jax.random.normal(jax.random.PRNGKey(2), (B, L, KV, D), jnp.float32)
 for pos in (0, 17, 63):
     ref = A.flash_attention(q, k, v, causal=True, q_offset=pos, chunk=16)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))  # KV=2 % 4 != 0 -> seqpar
+    mesh = make_mesh((2, 4), ("data", "model"))  # KV=2 % 4 != 0 -> seqpar
     def f(q, k, v):
         with SH.use_mesh(mesh, cfg=cfg):
             return A._decode_attention(q, k, v, pos, cfg, chunk=16)
@@ -176,7 +177,7 @@ from repro.data import make_batch_iterator
 from repro.launch import steps as S
 cfg = get_smoke_config("granite-3-8b").replace(dtype="float32")
 batch = next(make_batch_iterator(cfg, 4, 32, seed=0))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 state_struct = jax.eval_shape(lambda: S.init_train_state(cfg, jax.random.PRNGKey(0)))
 state_sh, batch_sh = S.train_shardings(cfg, mesh, state_struct,
                                        jax.eval_shape(lambda: batch))
@@ -472,7 +473,7 @@ cfg = get_smoke_config("llama-7b").replace(dtype="float32",
 params = M.init_params(cfg, jax.random.PRNGKey(0))
 params = factorize_params(params, cfg, rank_multiple=4)
 cache = M.init_cache(cfg, 4, 32)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 psh, csh = S.decode_shardings(cfg, mesh, jax.eval_shape(lambda: params),
                               jax.eval_shape(lambda: cache))
 step = jax.jit(S.make_serve_step(cfg, mesh), in_shardings=(
@@ -481,5 +482,39 @@ tok = jnp.zeros((4, 1), jnp.int32)
 next_tok, cache = step(params, cache, tok, 0)
 assert next_tok.shape == (4, 1)
 assert int(next_tok.min()) >= 0 and int(next_tok.max()) < cfg.vocab_size
+print("OK")
+""")
+
+
+def test_expert_parallel_server_matches_one_device():
+    """Server under a (1, 4) mesh: params initialized in their serving
+    layout hold 1/4 of each expert bank per device (the bank's expert axis,
+    not its layer-stack axis, is split), and greedy decode matches the same
+    model served on one device token for token (drop-free dispatch is
+    independent of how tokens are grouped)."""
+    run_child(COMMON + """
+import dataclasses
+from repro.launch.serve import Server
+from repro.models import model as M
+base = get_smoke_config("deepseek-v2-lite-16b")
+cfg = base.replace(num_layers=3, dtype="float32",
+                   moe=dataclasses.replace(base.moe, dispatch="dropfree"))
+key = jax.random.PRNGKey(0)
+devs = jax.devices()
+prompts = jax.random.randint(jax.random.PRNGKey(1), (4, 64), 0,
+                             cfg.vocab_size)
+outs = []
+for mesh in (make_mesh((1, 1), ("data", "model"), devices=devs[:1]),
+             make_mesh((1, 4), ("data", "model"), devices=devs[:4])):
+    shapes = jax.eval_shape(lambda: M.init_params(cfg, key))
+    psh = SH.param_shardings(shapes, mesh, mode="serve", cfg=cfg)
+    params = jax.jit(lambda: M.init_params(cfg, key), out_shardings=psh)()
+    srv = Server(cfg, params, max_len=80, batch=4, mesh=mesh)
+    outs.append(np.asarray(srv.generate(prompts, steps=8)))
+bank = srv.params["stages"][1][0]["ffn"]["experts"]["gate"]["w"]
+e = cfg.moe.num_experts
+assert bank.shape[:2] == (2, e), bank.shape
+assert {s.data.shape[:2] for s in bank.addressable_shards} == {(2, e // 4)}
+np.testing.assert_array_equal(outs[0], outs[1])
 print("OK")
 """)

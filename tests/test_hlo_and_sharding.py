@@ -7,6 +7,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch import hlo_analysis as H
+from repro.launch.mesh import make_mesh
 
 
 class TestHloAnalysis:
@@ -65,7 +66,7 @@ class TestHloAnalysis:
 class TestShardingRules:
     def setup_method(self):
         # a tiny mesh stands in: rules only read axis names/sizes
-        self.mesh = jax.make_mesh((1, 1), ("data", "model"))
+        self.mesh = make_mesh((1, 1), ("data", "model"))
 
     def test_param_rules(self):
         from repro.distributed import sharding as SH
@@ -94,7 +95,7 @@ class TestShardingRules:
 
     def test_indivisible_dims_fall_back_to_replication(self):
         from repro.distributed import sharding as SH
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         # simulate 16-way axis via a fake check: use mesh with size 1 -> all
         # dims divide; instead check _fit drops non-dividing axes
         spec = SH._fit(mesh, ["model", None], (7, 8))

@@ -86,6 +86,38 @@ class TestClosedForm:
         assert abs(via_cov - direct) / max(direct, 1e-6) < 1e-3
 
 
+class TestGramSVD:
+    """The TPU's SVD path (eigendecomposition of the smaller Gram matrix),
+    run here with the CPU eigensolver, against LAPACK's SVD."""
+
+    @pytest.mark.parametrize("shape", [(96, 40), (40, 96), (64, 64)])
+    def test_matches_lapack_svd(self, shape):
+        rng = np.random.default_rng(0)
+        # well-separated spectrum 1 .. 1e-2: the Gram squares it to 1e-4,
+        # far above fp32 rounding
+        r = min(shape)
+        u0, _ = np.linalg.qr(rng.standard_normal((shape[0], r)))
+        v0, _ = np.linalg.qr(rng.standard_normal((shape[1], r)))
+        s0 = np.logspace(0, -2, r)
+        mat = jnp.asarray((u0 * s0) @ v0.T, jnp.float32)
+        eigh = lambda a: tuple(jnp.linalg.eigh(a))  # noqa: E731
+        u, s, vt = LR._svd_via_gram(mat, eigh)
+        np.testing.assert_allclose(np.asarray(s), s0, rtol=1e-3, atol=1e-5)
+        for k in (1, r // 3, r):
+            got = (u[:, :k] * s[:k]) @ vt[:k]
+            want = (u0[:, :k] * s0[:k]) @ v0[:, :k].T
+            err = np.linalg.norm(np.asarray(got) - want) / np.linalg.norm(want)
+            assert err < 1e-4, (k, err)
+
+    def test_zero_directions_get_zero_vectors(self):
+        mat = jnp.zeros((8, 5), jnp.float32).at[0, 0].set(2.0)
+        eigh = lambda a: tuple(jnp.linalg.eigh(a))  # noqa: E731
+        u, s, vt = LR._svd_via_gram(mat, eigh)
+        assert np.isfinite(np.asarray(u)).all()
+        np.testing.assert_allclose(np.asarray((u * s) @ vt), np.asarray(mat),
+                                   atol=1e-6)
+
+
 class TestOptimality:
     @settings(max_examples=10, deadline=None)
     @given(seed=st.integers(0, 10_000), k=st.integers(1, 8))

@@ -27,6 +27,7 @@ from repro.core import pipeline as P
 from repro.core import streaming as S
 from repro.data import calibration_set
 from repro.kernels import ref
+from repro.launch.mesh import make_mesh
 from repro.models import layers as L
 from repro.models import model as M
 
@@ -180,7 +181,7 @@ class TestSeedParity:
         """Unknown strings and meshes without a data axis both get a clear
         ValueError, not a KeyError from deep inside the sharding rules."""
         cfg, params, calib = setup(n=4)
-        mesh = bad if bad == "bogus" else jax.make_mesh((1,), ("model",))
+        mesh = bad if bad == "bogus" else make_mesh((1,), ("model",))
         with pytest.raises(ValueError, match="calib_mesh"):
             compress_model(params, cfg, calib,
                            CompressConfig(refine=False, rank_multiple=1,
