@@ -100,16 +100,18 @@ def _kernel_row() -> dict:
     b, h, kv, d, l, r = SLOTS, 8, 2, 32, MAX_LEN, 24
     k1, k2 = jax.random.split(jax.random.PRNGKey(0))
     q = jax.random.normal(k1, (b, h, d), jnp.float32)
-    lk = jax.random.normal(k2, (b, l, r), jnp.float32)
-    lv = jax.random.normal(k1, (b, l, r), jnp.float32)
+    lk = jax.random.normal(k2, (b, r, l), jnp.float32)    # rank-major
+    lv = jax.random.normal(k1, (b, r, l), jnp.float32)
     uk = jax.random.normal(k2, (r, kv * d), jnp.float32) / 8
     uv = jax.random.normal(k1, (r, kv * d), jnp.float32) / 8
     lengths = jnp.full((b,), l // 2, jnp.int32)
     cos = jax.random.normal(k1, (l, d // 2), jnp.float32)
     sin = jax.random.normal(k2, (l, d // 2), jnp.float32)
     interp = jax.default_backend() != "tpu"
+    kn, vn = lk[:, :, 0], lv[:, :, 0]
     us = time_call(lambda: ops.flash_decode(q, lk, lv, uk, uv, lengths,
-                                            cos, sin, force_pallas=True,
+                                            cos, sin, kn, vn,
+                                            force_pallas=True,
                                             interpret=interp))
     return {"name": "flash_decode_kernel",
             "us": us, "meta": {"b": b, "h": h, "kv": kv, "d": d, "l": l,

@@ -193,14 +193,21 @@ def kernel_cases(key):
     lengths = jnp.asarray(
         np.random.default_rng(SEED + 1).integers(1, l + 1, b), jnp.int32)
 
-    def decode_ref(q, lk, lv, uk, uv, lens, c, s):
+    def decode_ref(q, lk, lv, uk, uv, lens, c, s, kn, vn):
         split = lambda u: u.reshape(r, kv, d).transpose(1, 0, 2)  # noqa: E731
-        return ref.flash_decode_ref(q, lk, lv, split(uk), split(uv), lens,
-                                    c, s)
+        rows = jnp.arange(b)
+        lk = lk.at[rows, :, lens - 1].set(kn)
+        lv = lv.at[rows, :, lens - 1].set(vn)
+        out = ref.flash_decode_ref(q, jnp.swapaxes(lk, 1, 2),
+                                   jnp.swapaxes(lv, 1, 2), split(uk),
+                                   split(uv), lens, c, s)
+        return out, lk, lv
 
+    # the latent cache is rank-major, (B, r, L)
     cases.append(("flash_decode", ops.flash_decode, decode_ref,
-                  (nrm(b, h, d), nrm(b, l, r), nrm(b, l, r), nrm(r, kv * d)
-                   / 16, nrm(r, kv * d) / 16, lengths, cos, sin)))
+                  (nrm(b, h, d), nrm(b, r, l), nrm(b, r, l), nrm(r, kv * d)
+                   / 16, nrm(r, kv * d) / 16, lengths, cos, sin, nrm(b, r),
+                   nrm(b, r))))
     return cases
 
 

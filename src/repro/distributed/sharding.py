@@ -342,11 +342,13 @@ def _cache_leaf_spec(kind: str, name: str, shape, mesh: Mesh) -> P:
             return _fit(mesh, [first, None, "model", None], shape)
         return _fit(mesh, [first, "model", None, None], shape)
     if name in ("c", "kr", "lk", "lv"):
-        # (B, L, r) MLA compressed / latent-GQA cache: SEQUENCE-sharded
-        # over model.  The
+        # (B, L, r) MLA compressed / (B, r, L) rank-major latent-GQA
+        # cache: SEQUENCE-sharded over model.  The
         # absorbed-decode score einsum contracts r against head-sharded
         # q_eff; r-sharding forces a full-cache all-gather per layer, while
         # L-sharding keeps scores local (softmax reduces with tiny psums).
+        if name in ("lk", "lv"):
+            return _fit(mesh, [first, None, "model"], shape)
         return _fit(mesh, [first, "model", None], shape)
     if name == "conv":                            # (B, W-1, C)
         return _fit(mesh, [first, None, "model"], shape)

@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-CACHE_VERSION = 1
+CACHE_VERSION = 2
 
 log = logging.getLogger(__name__)
 
@@ -334,15 +334,16 @@ def flash_candidates(lq: int, lk: int, d: int,
 def flash_decode_candidates(l: int, d: int, rk: int, rv: int, kv: int,
                             h: int,
                             dtype=jnp.float32) -> List[Candidate]:
-    """(bk,) lattice for the factorized flash-decode kernel.  VMEM:
-    double-buffered latent (bk, r_k) + (bk, r_v) tiles and (bk, D/2)
-    rope tables, the resident q/o (H, D) + U factors (KV, r, D), and the
-    fp32 (H, r_v) accumulator + (H, 1) stats scratch."""
+    """(bk,) lattice for the factorized flash-decode kernel.  bk is the
+    lane dim of the rank-major latent blocks.  VMEM: double-buffered
+    latent (r_k, bk) + (r_v, bk) tiles and (D/2, bk) rope tables, the
+    resident q/o (H, D) + U factors (KV, r, D), and the fp32 (H, r_v)
+    accumulator + (H, 1) stats scratch."""
     out = []
     eb = _bytes(dtype)
     lat = _LATTICES["flash_decode"]
     resident = (2 * h * d + kv * (rk + rv) * d) * eb
-    for bk in _pick_valid(l, lat["bk"], 8):
+    for bk in _pick_valid(l, lat["bk"], _LANE):
         vmem = (2 * (bk * rk + bk * rv + bk * d) * eb
                 + resident + (h * rv + 2 * h) * 4)
         waste = _round_up(l, bk) / l - 1
@@ -540,8 +541,10 @@ def flash_decode_blocks(b: int, h: int, kv: int, l: int, d: int,
                         interpret: bool = False) -> TuneResult:
     """Blocks for the factorized flash-decode kernel; ``l`` is the UNPADDED
     cache length (the caller pads it up to the returned block) and rk/rv
-    the lane-padded latent ranks."""
-    cands = flash_decode_candidates(l, d, rk, rv, kv, h, dtype)
+    the latent ranks as the cache holds them (the VMEM model counts them
+    lane-padded, as Mosaic lays them out)."""
+    cands = flash_decode_candidates(l, d, _round_up(rk, 128),
+                                    _round_up(rv, 128), kv, h, dtype)
     sig = (f"b{b}-h{h}-kv{kv}-l{l}-d{d}-rk{rk}-rv{rv}"
            f"-{jnp.dtype(dtype).name}-r{int(use_rope)}")
 
@@ -550,14 +553,15 @@ def flash_decode_blocks(b: int, h: int, kv: int, l: int, d: int,
         bk = c.blocks["bk"]
         lp = _round_up(l, bk)
         q = jnp.ones((b, h, d), dtype)
-        lkx = jnp.ones((b, lp, rk), dtype)
-        lvx = jnp.ones((b, lp, rv), dtype)
+        lkx = jnp.ones((b, rk, lp), dtype)
+        lvx = jnp.ones((b, rv, lp), dtype)
         uk = jnp.ones((kv, rk, d), dtype)
         uv = jnp.ones((kv, rv, d), dtype)
         lengths = jnp.full((b,), l, jnp.int32)
         cs = jnp.ones((lp, max(d // 2, 1)), dtype)
+        kn, vn = jnp.ones((b, rk), dtype), jnp.ones((b, rv), dtype)
         return (lambda *a: kern(*a, use_rope=use_rope, bk=bk,
                                 interpret=interpret),
-                (q, lkx, lvx, uk, uv, lengths, cs, cs))
+                (q, lkx, lvx, uk, uv, lengths, cs, cs, kn, vn))
 
     return _tune("flash_decode", sig, cands, thunk, mode, interpret)

@@ -201,22 +201,26 @@ _FLASH = KernelContract(
 
 
 def _fd_dims(p, blocks):
-    lp = _ru(p["l"], blocks["bk"])
-    return lp, _rl(p["rk"]), _rl(p["rv"])
+    # the wrapper clamps bk to L and pads L only off that grid; the ranks
+    # go in as the cache holds them
+    return _ru(p["l"], min(blocks["bk"], p["l"])), p["rk"], p["rv"]
 
 
 def _fd_abstract(p, blocks):
-    lp, rkl, rvl = _fd_dims(p, blocks)
+    lp, rk, rv = _fd_dims(p, blocks)
     b, h, kv, d = p["b"], p["h"], p["kv"], p["d"]
     args = (
         _struct((b, h, d)),                       # q
-        _struct((b, lp, rkl)),                    # latent K cache
-        _struct((b, lp, rvl)),                    # latent V cache
-        _struct((kv, rkl, d)),                    # U_k
-        _struct((kv, rvl, d)),                    # U_v
+        _struct((2, b, rk, lp)),                  # stacked latent K cache
+        _struct((2, b, rv, lp)),                  # stacked latent V cache
+        _struct((kv, rk, d)),                     # U_k
+        _struct((kv, rv, d)),                     # U_v
         _struct((b,), jnp.int32),                 # lengths
         _struct((lp, max(d // 2, 1))),            # cos
         _struct((lp, max(d // 2, 1))),            # sin
+        _struct((b, rk)),                         # this step's K latents
+        _struct((b, rv)),                         # this step's V latents
+        _struct((), jnp.int32),                   # layer
     )
     return jax.eval_shape(
         lambda *a: _decode_kernel(*a, use_rope=True,
@@ -225,12 +229,15 @@ def _fd_abstract(p, blocks):
 
 
 def _fd_expected(p, blocks):
-    return _struct((p["b"], p["h"], p["d"]))
+    lp, rk, rv = _fd_dims(p, blocks)
+    b = p["b"]
+    return (_struct((b, p["h"], p["d"])), _struct((2, b, rk, lp)),
+            _struct((2, b, rv, lp)))
 
 
 _DECODE = KernelContract(
     name="flash_decode",
-    align={"bk": _SUBLANE},
+    align={"bk": _LANE},
     probes=(
         {"b": 2, "h": 8, "kv": 2, "l": 1024, "d": 64, "rk": 128,
          "rv": 128},

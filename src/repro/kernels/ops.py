@@ -355,41 +355,68 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     return out[:, :, :lq0, :]
 
 
-def flash_decode(q, lk, lv, uk, uv, lengths, cos, sin, *, rope: bool = True,
-                 force_pallas: bool = False, interpret: bool = False):
-    """One decode step against the factorized latent KV cache.
+def _latent_write(cache, new, lengths, layer=None):
+    """XLA twin of the kernel's write: latents ``new`` (B, r) at position
+    lengths - 1 of a rank-major cache (B, r, L), or of layer ``layer`` of
+    a stacked (n, B, r, L) one."""
+    lead = () if layer is None else (layer,)
+    idx = lead + (jnp.arange(new.shape[0]), slice(None), lengths - 1)
+    return cache.at[idx].set(new.astype(cache.dtype))
 
-    q: (B, H, D) current-step queries (already RoPE'd); lk/lv: (B, L,
-    r_k / r_v) latent caches; uk/uv: (r_k, KV·D) / (r_v, KV·D) — the "u"
-    factor leaves exactly as stored in params; lengths: (B,) int32 live
-    prefix per slot; cos/sin: (L, D//2) rope tables at absolute positions.
-    Returns (B, H, D).
 
-    Latent ranks are lane-padded with zero columns (exact: zero latent
-    dims contribute nothing through U); L is padded to the tuned block and
-    masked via ``lengths``.  The head dim stays TRUE-sized so the
-    in-kernel RoPE rotate-half pairing is preserved.
+def flash_decode(q, lk, lv, uk, uv, lengths, cos, sin, lk_new, lv_new, *,
+                 layer=None, rope: bool = True, force_pallas: bool = False,
+                 interpret: bool = False):
+    """One decode step against the factorized latent KV cache: write the
+    step's latents, then attend.
+
+    q: (B, H, D) current-step queries (already RoPE'd); lk/lv: rank-major
+    latent caches (B, r_k / r_v, L), or a scanned stage's stacked
+    (n_layers, B, r_k / r_v, L) with ``layer`` the (traced) index of the
+    layer to read; uk/uv: (r_k, KV·D) / (r_v, KV·D) — the "u" factor
+    leaves exactly as stored in params; lengths: (B,) int32 live prefix
+    per slot; cos/sin: (L, D//2) rope tables at absolute positions;
+    lk_new/lv_new: (B, r_k / r_v) latents of the step's token, written at
+    position lengths - 1.  Returns (out (B, H, D), lk, lv).
+
+    The kernel writes and reads the caches where they lie: the ranks are
+    not padded, and a stacked cache is read at ``layer`` through the
+    kernel's index_map.  Only an L off the tuned block grid is padded
+    (masked via ``lengths``): the write then goes through XLA, and the
+    kernel attends a padded copy of the layer.  The head dim stays
+    TRUE-sized so the in-kernel RoPE rotate-half pairing is preserved.
     """
     b, h, d = q.shape
     kv = uk.shape[-1] // d
     uk3 = uk.reshape(uk.shape[0], kv, d).transpose(1, 0, 2)  # (KV, r_k, D)
     uv3 = uv.reshape(uv.shape[0], kv, d).transpose(1, 0, 2)
+    take = functools.partial(jax.lax.dynamic_index_in_dim, index=layer,
+                             axis=0, keepdims=False)
     if not (use_pallas() or force_pallas):
-        return ref.flash_decode_ref(q, lk, lv, uk3, uv3, lengths, cos, sin,
-                                    rope=rope)
-    l0 = lk.shape[1]
-    lk, _ = _pad_dim(lk, 2, 128)
-    lv, _ = _pad_dim(lv, 2, 128)
-    uk3, _ = _pad_dim(uk3, 1, 128)
-    uv3, _ = _pad_dim(uv3, 1, 128)
+        lk = _latent_write(lk, lk_new, lengths, layer)
+        lv = _latent_write(lv, lv_new, lengths, layer)
+        lkl, lvl = (lk, lv) if layer is None else (take(lk), take(lv))
+        out = ref.flash_decode_ref(q, jnp.swapaxes(lkl, 1, 2),
+                                   jnp.swapaxes(lvl, 1, 2), uk3, uv3,
+                                   lengths, cos, sin, rope=rope)
+        return out, lk, lv
+    l0 = lk.shape[-1]
     tune = autotune.flash_decode_blocks(
-        b, h, kv, l0, d, lk.shape[-1], lv.shape[-1], dtype=q.dtype,
+        b, h, kv, l0, d, lk.shape[-2], lv.shape[-2], dtype=q.dtype,
         use_rope=rope, interpret=interpret)
-    bk = tune.blocks["bk"]
-    lk, _ = _pad_dim(lk, 1, bk)
-    lv, _ = _pad_dim(lv, 1, bk)
+    bk = min(tune.blocks["bk"], l0)
+    if l0 % bk == 0:
+        return _flash_decode_kernel(q, lk, lv, uk3, uv3, lengths, cos, sin,
+                                    lk_new, lv_new, layer, use_rope=rope,
+                                    bk=bk, interpret=interpret)
+    lk = _latent_write(lk, lk_new, lengths, layer)
+    lv = _latent_write(lv, lv_new, lengths, layer)
+    lkl, lvl = (lk, lv) if layer is None else (take(lk), take(lv))
+    lkl, _ = _pad_dim(lkl, 2, bk)
+    lvl, _ = _pad_dim(lvl, 2, bk)
     cos, _ = _pad_dim(cos, 0, bk)
     sin, _ = _pad_dim(sin, 0, bk)
-    return _flash_decode_kernel(q, lk, lv, uk3, uv3, lengths, cos, sin,
-                                use_rope=rope, bk=min(bk, lk.shape[1]),
-                                interpret=interpret)
+    out, _, _ = _flash_decode_kernel(q, lkl, lvl, uk3, uv3, lengths, cos,
+                                     sin, lk_new, lv_new, use_rope=rope,
+                                     bk=bk, interpret=interpret)
+    return out, lk, lv

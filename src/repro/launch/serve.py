@@ -38,11 +38,17 @@ before the text tokens.
   accounting, with ``admit_host``, the admission's host seconds outside
   its blocking first-token copy; ``decode_step_times`` keeps the per-step
   decode wall times of the last run for throughput accounting.
+* The decode step writes and reads a scanned stage's latent cache where
+  it lies, at the layer index; ``decode_inplace_layers``, set when the
+  decode program is first traced (None before), is ``(in_place, sliced)``:
+  the attention layers that take that path, and those whose cache is
+  sliced out per layer and written back (``models.model.decode_paths``).
 * Spans (``repro.analysis.spans``, off unless enabled) mark each
   admission (``repro.serve.admit``: slot take, prefill per chunk, slot
-  put, first-token copy), each decode step (``repro.serve.step``:
-  dispatch, wait, bookkeeping) and each wait for an arrival, with the
-  request id on every span of an admission.
+  put, first-token copy), each decode step (``repro.serve.step``, with
+  ``live`` slots and the ``inplace``/``sliced`` layer counts: dispatch,
+  wait, bookkeeping) and each wait for an arrival, with the request id
+  on every span of an admission.
 
   python -m repro.launch.serve --arch qwen3-0.6b --smoke --ratio 0.6
 """
@@ -220,8 +226,16 @@ class ContinuousBatchingServer:
         self._exact = (cfg.family in ("ssm", "hybrid")
                        or cfg.attention == "sliding_mix")
         mesh = serving_mesh(mesh)
-        self._decode = jax.jit(S.make_serve_step(cfg, mesh),
-                               donate_argnums=(1,))
+        self.decode_inplace_layers = None
+        step = S.make_serve_step(cfg, mesh)
+
+        @functools.wraps(step)
+        def serve_step(params, cache, tokens, pos):
+            # runs while the decode program is traced
+            self.decode_inplace_layers = M.decode_paths(cfg, cache)
+            return step(params, cache, tokens, pos)
+
+        self._decode = jax.jit(serve_step, donate_argnums=(1,))
         self._pre_whole = jax.jit(S.make_slot_prefill_step(cfg, mesh,
                                                            chunked=False))
         self._pre_chunk = jax.jit(S.make_slot_prefill_step(cfg, mesh,
@@ -380,9 +394,10 @@ class ContinuousBatchingServer:
             # ---- one batched decode step over ALL slots (parked slots sit
             # at position 0; their writes are overwritten or masked)
             live = sum(s is not None for s in active)
+            in_place, sliced = self.decode_inplace_layers or (None, None)
             t_step = time.monotonic()
             with span("repro.serve.step", step=len(self.decode_step_times),
-                      live=live):
+                      live=live, inplace=in_place, sliced=sliced):
                 with span("repro.serve.dispatch"):
                     tok_dev, cache = self._decode(self.params, cache,
                                                   jnp.asarray(tokens_np),

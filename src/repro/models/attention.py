@@ -519,7 +519,7 @@ def mla_prefill_cached(p, x, cache_c, cache_kr, start, cfg, cos, sin):
 # When the k/v projections are factorized (w = v @ u, bias-free), the
 # per-token cache state the model actually needs is the rank-r latent
 # l = x @ v — the MLA trick applied to ordinary GQA.  Decode stores only
-# (B, Lmax, r_k) + (B, Lmax, r_v) and the flash-decode kernel up-projects
+# (B, r_k, Lmax) + (B, r_v, Lmax) and the flash-decode kernel up-projects
 # keys in-kernel (U_k) while keeping the value accumulator in latent space
 # (U_v applied once per head in the epilogue), so the compression ratio
 # shows up directly as cache bytes AND decode FLOPs.
@@ -553,10 +553,10 @@ def _latent_kv(p, x):
 
 def gqa_prefill_latent(p, x, cache_lk, cache_lv, start, cfg, cos, sin, *,
                        theta: float, rope: bool = True, chunk: int = 1024):
-    """Prefill into the latent cache: write this chunk's rank-r latents at
-    ``start``, up-project the whole cache once, and flash-attend with
-    absolute-position masking.  Used for whole prompts (start=0) and for
-    chunked prefill alike."""
+    """Prefill into the rank-major latent cache (B, r, Lmax): write this
+    chunk's rank-r latents at ``start``, up-project the whole cache once,
+    and flash-attend with absolute-position masking.  Used for whole
+    prompts (start=0) and for chunked prefill alike."""
     b, l, _ = x.shape
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = L.linear(p["wq"], x).reshape(b, l, h, hd)
@@ -564,14 +564,18 @@ def gqa_prefill_latent(p, x, cache_lk, cache_lv, start, cfg, cos, sin, *,
         q = L.apply_rope(q, cos, sin)
     lk_c, lv_c = _latent_kv(p, x)
     cache_lk = jax.lax.dynamic_update_slice_in_dim(
-        cache_lk, lk_c.astype(cache_lk.dtype), start, axis=1)
+        cache_lk, jnp.swapaxes(lk_c, 1, 2).astype(cache_lk.dtype), start,
+        axis=2)
     cache_lv = jax.lax.dynamic_update_slice_in_dim(
-        cache_lv, lv_c.astype(cache_lv.dtype), start, axis=1)
-    lmax = cache_lk.shape[1]
-    k_all = (cache_lk @ p["wk"]["u"].astype(cache_lk.dtype)
-             ).reshape(b, lmax, kv, hd)
-    v_all = (cache_lv @ p["wv"]["u"].astype(cache_lv.dtype)
-             ).reshape(b, lmax, kv, hd)
+        cache_lv, jnp.swapaxes(lv_c, 1, 2).astype(cache_lv.dtype), start,
+        axis=2)
+    lmax = cache_lk.shape[2]
+    k_all = jnp.einsum("brl,rn->bln", cache_lk,
+                       p["wk"]["u"].astype(cache_lk.dtype)
+                       ).reshape(b, lmax, kv, hd)
+    v_all = jnp.einsum("brl,rn->bln", cache_lv,
+                       p["wv"]["u"].astype(cache_lv.dtype)
+                       ).reshape(b, lmax, kv, hd)
     if rope:
         cos_all, sin_all = L.rope_table(jnp.arange(lmax), hd, theta)
         k_all = L.apply_rope(k_all, cos_all, sin_all)
@@ -581,12 +585,15 @@ def gqa_prefill_latent(p, x, cache_lk, cache_lv, start, cfg, cos, sin, *,
 
 
 def gqa_decode_latent(p, x, cache_lk, cache_lv, pos, cfg, cos, sin, *,
-                      theta: float, rope: bool = True):
+                      theta: float, rope: bool = True, layer=None):
     """One-token decode against the factorized latent cache.
 
-    x: (B, 1, d); caches (B, Lmax, r_k/r_v); pos scalar or per-slot (B,).
-    Dispatches to ``kernels.ops.flash_decode`` (Pallas on TPU, reference
-    einsums elsewhere) with per-slot lengths = pos + 1.
+    x: (B, 1, d); caches rank-major (B, r_k/r_v, Lmax), or a scanned
+    stage's stacked (n, B, r_k/r_v, Lmax) with ``layer`` the index of this
+    layer (written and read where it lies, never sliced out); pos scalar
+    or per-slot (B,).  Dispatches to ``kernels.ops.flash_decode`` (Pallas
+    on TPU, reference einsums elsewhere), which writes this token's
+    latents at pos and attends with per-slot lengths = pos + 1.
     """
     from repro.kernels import ops as KO
     b = x.shape[0]
@@ -595,13 +602,11 @@ def gqa_decode_latent(p, x, cache_lk, cache_lv, pos, cfg, cos, sin, *,
     if rope:
         q = L.apply_rope(q, cos, sin)
     lk_t, lv_t = _latent_kv(p, x)
-    cache_lk = _cache_write(cache_lk, lk_t, pos)
-    cache_lv = _cache_write(cache_lv, lv_t, pos)
     lengths = jnp.broadcast_to(jnp.asarray(pos) + 1, (b,)).astype(jnp.int32)
-    lmax = cache_lk.shape[1]
+    lmax = cache_lk.shape[-1]
     cos_all, sin_all = L.rope_table(jnp.arange(lmax), hd, theta)
-    o = KO.flash_decode(q[:, 0], cache_lk, cache_lv,
-                        p["wk"]["u"], p["wv"]["u"], lengths,
-                        cos_all, sin_all, rope=rope)
+    o, cache_lk, cache_lv = KO.flash_decode(
+        q[:, 0], cache_lk, cache_lv, p["wk"]["u"], p["wv"]["u"], lengths,
+        cos_all, sin_all, lk_t[:, 0], lv_t[:, 0], layer=layer, rope=rope)
     return (L.linear(p["wo"], o.reshape(b, 1, h * hd).astype(x.dtype)),
             cache_lk, cache_lv)

@@ -208,8 +208,9 @@ def init_sub_cache(kind: str, cfg, batch: int, max_len: int, dtype,
                    params=None):
     """Zero cache for one sub-block.  When ``params`` (the sub-block's param
     dict) is given and the kv projections are factorized, attention caches
-    use the latent {"lk", "lv"} layout (rank-r per token) instead of dense
-    {"k", "v"} — the AA-SVD serving-path footprint win."""
+    use the latent {"lk", "lv"} layout (rank-r per token, rank-major
+    (B, r, L)) instead of dense {"k", "v"} — the AA-SVD serving-path
+    footprint win."""
     kv, hd = cfg.num_kv_heads, cfg.head_dim
     if kind in ("mamba1", "mamba2"):
         init = S.mamba1_init_state if kind == "mamba1" else S.mamba2_init_state
@@ -226,8 +227,10 @@ def init_sub_cache(kind: str, cfg, batch: int, max_len: int, dtype,
         return {}
     ranks = latent_layout(kind, params, cfg)
     if ranks is not None:
-        base = {"lk": jnp.zeros((batch, max_len, ranks[0]), dtype),
-                "lv": jnp.zeros((batch, max_len, ranks[1]), dtype)}
+        # rank-major (B, r, L): the layout the TPU gives a rank off the
+        # lane grid anyway, which the flash-decode kernel reads in place
+        base = {"lk": jnp.zeros((batch, ranks[0], max_len), dtype),
+                "lv": jnp.zeros((batch, ranks[1], max_len), dtype)}
     else:
         base = {"k": jnp.zeros((batch, max_len, kv, hd), dtype),
                 "v": jnp.zeros((batch, max_len, kv, hd), dtype)}
@@ -332,7 +335,10 @@ def prefill_sub_block(kind: str, p, x, cache, cfg, ctx):
 
 
 def decode_sub_block(kind: str, p, x, cache, cfg, ctx):
-    """x: (B, 1, d) -> (x, new_cache).  ctx['pos'] is the current position."""
+    """x: (B, 1, d) -> (x, new_cache).  ctx['pos'] is the current position.
+    ``ctx['layer']``, where present, is the index of this layer in a
+    scanned stage whose latent {"lk", "lv"} leaves arrive stacked: they are
+    written and read at that layer where they lie."""
     pos = ctx["pos"]
     if kind in ("mamba1", "mamba2"):
         dec = S.mamba1_decode if kind == "mamba1" else S.mamba2_decode
@@ -353,7 +359,8 @@ def decode_sub_block(kind: str, p, x, cache, cfg, ctx):
     elif "lk" in cache:
         attn_out, cache["lk"], cache["lv"] = A.gqa_decode_latent(
             p["attn"], h, cache["lk"], cache["lv"], pos, cfg, cos, sin,
-            theta=_theta(kind, cfg), rope=kind != "dec_attn")
+            theta=_theta(kind, cfg), rope=kind != "dec_attn",
+            layer=ctx.get("layer"))
     else:
         attn_out, cache["k"], cache["v"] = A.gqa_decode(
             p["attn"], h, cache["k"], cache["v"], pos, cfg, cos, sin,

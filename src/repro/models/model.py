@@ -266,13 +266,27 @@ def cache_slot_put(cfg, cache, slot_cache, slot) -> PyTree:
 # prefill / decode
 
 
+# latent cache leaves that a decode step writes and reads where they lie
+_IN_PLACE = ("lk", "lv")
+
+
+def _latent(cache) -> bool:
+    """Whether a sub-block's cache is the latent {"lk", "lv"} layout,
+    which the flash-decode kernel reads where it lies."""
+    return isinstance(cache, dict) and "lk" in cache
+
+
 def _run_stage_cached(stage: B.Stage, stage_params, shared, x, stage_cache,
-                      cfg, ctx, fn):
-    """fn = B.prefill_sub_block (returns x, cache, aux) or decode wrapper."""
+                      cfg, ctx, fn, *, in_place: bool = False):
+    """fn = B.prefill_sub_block (returns x, cache, aux) or decode wrapper.
+
+    ``in_place`` (decode): latent leaves of a scanned stage stay stacked;
+    the sub-block gets them whole with ``ctx["layer"]`` and returns them
+    updated, so no layer of them is sliced out or written back."""
 
     from repro.distributed import sharding as SH
 
-    def iteration(x, per_kind_params, per_kind_cache):
+    def iteration(x, per_kind_params, per_kind_cache, ctx):
         new_cache = []
         aux = jnp.zeros((), jnp.float32)
         for kind, p, c in zip(stage.kinds, per_kind_params, per_kind_cache):
@@ -294,29 +308,39 @@ def _run_stage_cached(stage: B.Stage, stage_params, shared, x, stage_cache,
         # alias — a full O(cache) copy per layer per decode step (528 GiB per
         # token on llama decode_32k).  Carried-buffer dynamic updates alias
         # in place; stacked layer params are dynamic-index reads (slice-only
-        # traffic).
+        # traffic).  A layer slice that feeds a Pallas call is a buffer of
+        # its own, though, so in-place leaves are never sliced.
+        keep = [in_place and _latent(c) for c in stage_cache]
+
+        def take(leaf, i):
+            return jax.lax.dynamic_index_in_dim(leaf, i, 0, keepdims=False)
+
+        def put(leaf, upd, i):
+            return jax.lax.dynamic_update_index_in_dim(
+                leaf, upd.astype(leaf.dtype), i, 0)
+
         def body(i, val):
             x, cache, aux = val
-            p_i = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
-                                                       keepdims=False),
-                tuple(stage_params))
-            c_i = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
-                                                       keepdims=False),
-                cache)
-            x, c_new, a = iteration(x, list(p_i), list(c_i))
-            cache = jax.tree.map(
-                lambda buf, upd: jax.lax.dynamic_update_index_in_dim(
-                    buf, upd.astype(buf.dtype), i, 0),
-                cache, tuple(c_new))
+            p_i = jax.tree.map(lambda a: take(a, i), tuple(stage_params))
+            c_i = [{k: v if k in _IN_PLACE else take(v, i)
+                    for k, v in c.items()} if kp
+                   else jax.tree.map(lambda a: take(a, i), c)
+                   for c, kp in zip(cache, keep)]
+            x, c_new, a = iteration(x, list(p_i), c_i,
+                                    {**ctx, "layer": i} if any(keep)
+                                    else ctx)
+            cache = tuple(
+                {k: u if k in _IN_PLACE else put(c[k], u, i)
+                 for k, u in cn.items()} if kp
+                else jax.tree.map(lambda buf, upd: put(buf, upd, i), c, cn)
+                for c, cn, kp in zip(cache, c_new, keep))
             return x, cache, aux + a
 
         x, new_cache, aux = jax.lax.fori_loop(
             0, stage.n, body,
             (x, tuple(stage_cache), jnp.zeros((), jnp.float32)))
         return x, list(new_cache), aux
-    x, new_cache, aux = iteration(x, stage_params, stage_cache)
+    x, new_cache, aux = iteration(x, stage_params, stage_cache, ctx)
     return x, new_cache, aux
 
 
@@ -355,10 +379,25 @@ def prefill(params, cfg, batch, cache, *, pos: int = 0,
     return logits, new_cache
 
 
+def decode_paths(cfg, cache):
+    """``(in_place, sliced)``: how many attention layers :func:`decode_step`
+    writes and reads where their latent cache lies (stacked, at the layer
+    index, in a scanned stage), and how many take the other path (a
+    dense, MLA or ring cache, sliced out of a scanned stage per layer and
+    written back).  ``cache`` may be arrays or shape structs."""
+    counts = [0, 0]
+    for st, per_kind in zip(B.stage_program(cfg), cache):
+        for kind, c in zip(st.kinds, per_kind):
+            if kind not in ("mamba1", "mamba2"):
+                counts[0 if _latent(c) else 1] += st.n
+    return tuple(counts)
+
+
 def decode_step(params, cfg, cache, tokens, pos, *, constrain=None):
     """One decode step.  tokens: (B, 1) int32; pos: scalar int32 (0-based
     absolute position of this token) or a per-slot (B,) vector when every
-    scheduler slot sits at its own length.  Returns (logits (B, V), cache)."""
+    scheduler slot sits at its own length.  Returns (logits (B, V), cache).
+    :func:`decode_paths` says which layers keep their cache in place."""
     dtype = jnp.dtype(cfg.dtype)
     x = L.embed(params["embed"], tokens, dtype)
     per_slot = jnp.ndim(pos) == 1
@@ -377,7 +416,7 @@ def decode_step(params, cfg, cache, tokens, pos, *, constrain=None):
     new_cache = []
     for st, sp, sc in zip(B.stage_program(cfg), params["stages"], cache):
         x, c, _ = _run_stage_cached(st, sp, params.get("shared", {}), x, sc,
-                                    cfg, ctx, dec)
+                                    cfg, ctx, dec, in_place=True)
         new_cache.append(c)
     hidden = L.apply_norm(params["final_norm"], x, eps=cfg.norm_eps)
     logits = logits_from_hidden(params, cfg, hidden)[:, 0]

@@ -8,7 +8,8 @@ compiler rejects what interpret mode accepts (block shapes off the (8, 128)
 tiling, scoped-memory overruns).  Widths are qwen3-0.6b's (d_model 1024,
 16 heads / 8 KV heads of 128, d_ff 3072) over 4096 calibration tokens;
 ``grouped_matmul`` uses deepseek-v2-lite's expert widths (2048 → 1408,
-16 experts).
+16 experts), and ``flash_decode_stacked`` granite-3-8b's stacked latent
+cache.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library.
@@ -71,11 +72,23 @@ def _cases(dtype):
         lambda q, k, v: ops.flash_attention(q, k, v, force_pallas=True),
         [sds(1, h, l, d), sds(1, kv, l, d), sds(1, kv, l, d)])
     cases["flash_decode"] = (
-        lambda q, lk, lv, uk, uv, lens, c, s: ops.flash_decode(
-            q, lk, lv, uk, uv, lens, c, s, force_pallas=True),
-        [sds(b, h, d), sds(b, l, r), sds(b, l, r), sds(r, kv * d),
+        lambda q, lk, lv, uk, uv, lens, c, s, kn, vn: ops.flash_decode(
+            q, lk, lv, uk, uv, lens, c, s, kn, vn, force_pallas=True),
+        [sds(b, h, d), sds(b, r, l), sds(b, r, l), sds(r, kv * d),
          sds(r, kv * d), sds(b, dt=jnp.int32), sds(l, d // 2),
-         sds(l, d // 2)])
+         sds(l, d // 2), sds(b, r), sds(b, r)])
+    # granite-3-8b's serving shape: the stacked rank-major latent cache of
+    # 20 layers, 64 slots and 2048 positions at rank 496 (off the lane
+    # grid), read at a layer index
+    n, gb, gh, gr = 20, 64, 32, 496
+    cases["flash_decode_stacked"] = (
+        lambda q, lk, lv, uk, uv, lens, c, s, kn, vn, i: ops.flash_decode(
+            q, lk, lv, uk, uv, lens, c, s, kn, vn, layer=i,
+            force_pallas=True),
+        [sds(gb, gh, d), sds(n, gb, gr, l), sds(n, gb, gr, l),
+         sds(gr, kv * d), sds(gr, kv * d), sds(gb, dt=jnp.int32),
+         sds(l, d // 2), sds(l, d // 2), sds(gb, gr), sds(gb, gr),
+         sds(dt=jnp.int32)])
     return cases
 
 
@@ -113,3 +126,18 @@ def test_solve_lowers_to_jacobi_eigh_on_tpu(one_chip, solve):
     text = jax.jit(SOLVES[solve]).lower(*args).as_text()
     assert "@Eigh" in text
     assert "stablehlo.while" not in text
+
+
+def test_stacked_flash_decode_reads_cache_in_place(one_chip):
+    """At granite-3-8b's stacked latent cache the TPU compiler gives
+    ``flash_decode`` the cache as it lies: the operands alias the outputs
+    and no temporary holds even one layer's copy (a token-major cache,
+    laid out rank-minor by the TPU, was transposed whole on every call)."""
+    fn, shapes = _cases(jnp.bfloat16)["flash_decode_stacked"]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    mem = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        *args).compile().memory_analysis()
+    n, b, r, l = shapes[1][0]
+    assert mem.alias_size_in_bytes == 2 * n * b * r * l * 2
+    assert mem.temp_size_in_bytes < b * r * l * 2
